@@ -166,6 +166,8 @@ def bench_main(name: str, main: Callable, argv: Optional[List[str]] = None
     ap.add_argument("--json", action="store_true",
                     help=f"also write BENCH_{name}.json")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     set_config(quick=args.quick, tiny=args.tiny)
     accepted = set(inspect.signature(main).parameters)
     kw = {k: getattr(args, k) for k in ("quick", "tiny") if k in accepted}

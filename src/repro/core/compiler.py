@@ -279,6 +279,13 @@ class CompiledScript:
                                       use_pallas=use_pallas,
                                       interpret=interpret)
 
+    def online_batch_fast_hlo(self, store: "timestore.OnlineStore",
+                              keys: Sequence[int], ts: Sequence[int],
+                              values: Dict[str, Sequence[float]]) -> str:
+        """Compiled HLO text of the executable ``online_batch_fast``
+        runs for this batch."""
+        return _drv.online_batch_fast_hlo(self, store, keys, ts, values)
+
     # -- pre-aggregation plumbing -------------------------------------------
     def init_preagg_states(self) -> Dict[int, Any]:
         return {wi: w.preagg.init_state()

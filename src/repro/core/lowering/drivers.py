@@ -66,6 +66,7 @@ __all__ = [
     "offline_branch", "offline_reference_serial", "online_fn",
     "online_window_unit", "online_fast_fn", "pad_batch", "store_fn",
     "online", "online_batch", "online_sharded_batch", "online_batch_fast",
+    "online_batch_fast_hlo",
 ]
 
 
@@ -384,7 +385,6 @@ def offline_sharded(cs, tables, mesh=None, n_shards: Optional[int] = None,
 
         from jax.sharding import PartitionSpec as P
 
-        from ...distributed.sharding import shard_map_compat
         tm = jax.tree_util.tree_map
 
         def mapped(stacked, flats):
@@ -394,7 +394,7 @@ def offline_sharded(cs, tables, mesh=None, n_shards: Optional[int] = None,
             return tm(lambda x: x[None], per_shard(devs))
 
         def fn(stacked, flats):
-            return shard_map_compat(
+            return jax.shard_map(
                 mapped, mesh=mesh, in_specs=(P(axis), P()),
                 out_specs=P(axis))(stacked, flats)
         return jax.jit(fn)
@@ -650,8 +650,6 @@ def _sharded_store_fn(cs, store, use_pre: bool, b_pad: int):
     else:
         from jax.sharding import PartitionSpec as P
 
-        from ...distributed.sharding import shard_map_compat
-
         tm = jax.tree_util.tree_map
 
         def mapped(states, kb, tb, vb, pre):
@@ -662,7 +660,7 @@ def _sharded_store_fn(cs, store, use_pre: bool, b_pad: int):
             return tm(lambda x: x[None], out)
 
         spec = P(store.axis)
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             mapped, mesh=store.mesh, in_specs=(spec,) * 5,
             out_specs=spec))
     cs._online_fns[local_key] = fn
@@ -673,6 +671,23 @@ def online_batch_fast(cs, store, keys, ts, values, use_pallas=None,
                       interpret=None) -> Dict[str, np.ndarray]:
     """Fused megakernel fast path entry (see ``online_fast_fn``) —
     bitwise equal to ``online_batch``."""
+    fn, args, b = _fast_step(cs, store, keys, ts, values, use_pallas,
+                             interpret)
+    out = fn(*args)
+    return {k: np.asarray(v)[:b] for k, v in out.items()}
+
+
+def online_batch_fast_hlo(cs, store, keys, ts, values, use_pallas=None,
+                          interpret=None) -> str:
+    """Compiled HLO text of the executable ``online_batch_fast`` runs
+    for this batch (e.g. to check that it holds the Pallas kernel)."""
+    fn, args, _ = _fast_step(cs, store, keys, ts, values, use_pallas,
+                             interpret)
+    return fn.lower(*args).compile().as_text()
+
+
+def _fast_step(cs, store, keys, ts, values, use_pallas, interpret):
+    """(cached jitted step, its arguments, real batch size)."""
     ok, why = cs.fast_batch_eligible()
     if not ok:
         raise ValueError(f"script not eligible for fused path: {why}")
@@ -693,8 +708,7 @@ def online_batch_fast(cs, store, keys, ts, values, use_pallas=None,
             online_fast_fn, cs, use_pallas=use_pallas,
             interpret=interpret), donate_argnums=donate))
     vals = {k: jnp.asarray(v) for k, v in vals_np.items()}
-    out = fn(store.tables, jnp.asarray(keys), jnp.asarray(tsa), vals)
-    return {k: np.asarray(v)[:b] for k, v in out.items()}
+    return fn, (store.tables, jnp.asarray(keys), jnp.asarray(tsa), vals), b
 
 
 def online_window_unit(states, members: Sequence[LoweredWindow], key, ts,

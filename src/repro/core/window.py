@@ -25,6 +25,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..distributed.sharding import vary_like
 from .functions import Aggregator, Leaf
 
 __all__ = [
@@ -115,8 +116,10 @@ def first_geq(ts_sorted: jnp.ndarray, targets: jnp.ndarray,
         hi_ = jnp.where(go_right | (lo_ >= hi_), hi_, mid)
         return lo_, hi_
 
-    lo_f, _ = jax.lax.fori_loop(0, steps, body,
-                                (lo.astype(jnp.int32), hi.astype(jnp.int32)))
+    ops = (ts_sorted, targets, lo, hi)
+    lo_f, _ = jax.lax.fori_loop(
+        0, steps, body, (vary_like(jnp.asarray(lo, jnp.int32), *ops),
+                         vary_like(jnp.asarray(hi, jnp.int32), *ops)))
     return lo_f
 
 

@@ -2,4 +2,4 @@
 
 from .sharding import (param_pspecs, batch_pspec, cache_pspecs,  # noqa: F401
                        named_shardings, key_shard_mesh,
-                       stacked_store_sharding, shard_map_compat)
+                       stacked_store_sharding, vary_like)

@@ -20,7 +20,8 @@ store is *key*-partitioned — every row of a given partition key lives on
 exactly one shard, so window folds never cross shards.  ``key_shard_mesh``
 builds the 1-D mesh, ``stacked_store_sharding`` places a shard-stacked
 store pytree (leading dim = shard) with one shard per device, and
-``shard_map_compat`` papers over the jax 0.4/0.5 shard_map location.
+``vary_like`` gives loop carries inside ``jax.shard_map`` bodies the
+varying-axes type their loop bodies produce.
 Routing itself (key -> shard) is host-side hash + rebalance, owned by
 ``storage.timestore.ShardedOnlineStore``.
 """
@@ -31,22 +32,27 @@ import re
 from typing import Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["auto_pspec", "param_pspecs", "batch_pspec", "cache_pspecs",
            "named_shardings", "key_shard_mesh", "stacked_store_sharding",
-           "shard_map_compat"]
-
-try:                                   # jax >= 0.5 top-level API
-    _shard_map = jax.shard_map
-except AttributeError:                 # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+           "vary_like"]
 
 
-def shard_map_compat(*args, **kwargs):
-    """``jax.shard_map`` / ``jax.experimental.shard_map`` shim."""
-    return _shard_map(*args, **kwargs)
+def vary_like(x, *operands):
+    """``x`` cast to varying over every manual mesh axis that any of
+    ``operands`` varies over.  Under ``jax.shard_map`` a loop carry that
+    the body mixes with per-shard data leaves the body varying, and the
+    carry's initial value must already have that type.  Outside
+    ``shard_map`` nothing varies and ``x`` comes back unchanged."""
+    x = jnp.asarray(x)
+    axes = set().union(*(jax.typeof(o).vma for o in operands))
+    axes -= jax.typeof(x).vma
+    if not axes:  # lint: ok J001 — vma is static type data, not a value
+        return x
+    return jax.lax.pcast(x, tuple(sorted(axes)), to="varying")
 
 
 def key_shard_mesh(n_shards: Optional[int] = None,

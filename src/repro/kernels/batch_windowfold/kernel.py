@@ -67,7 +67,7 @@ def batch_windowfold_pallas(keys: jnp.ndarray, ts: jnp.ndarray,
                             vals: jnp.ndarray, qkey: jnp.ndarray,
                             qt0: jnp.ndarray, qt1: jnp.ndarray,
                             bb: int = DEFAULT_BB, bc: int = DEFAULT_BC,
-                            interpret: bool = True) -> jnp.ndarray:
+                            *, interpret: bool) -> jnp.ndarray:
     c, f = vals.shape
     b = qkey.shape[0]
     bb = min(bb, _ceil_mult(b, 8))

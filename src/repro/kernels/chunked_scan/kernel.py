@@ -67,7 +67,7 @@ def _shift_down(x, k):
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def linear_scan_pallas(a: jnp.ndarray, b: jnp.ndarray,
                        chunk: int = DEFAULT_CHUNK,
-                       interpret: bool = True) -> jnp.ndarray:
+                       *, interpret: bool) -> jnp.ndarray:
     """a, b: (B, T, D) -> y: (B, T, D).  T must be a multiple of chunk
     (callers pad; padding steps should use a=1, b=0 to be no-ops)."""
     bsz, t, d = a.shape

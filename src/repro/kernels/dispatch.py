@@ -9,7 +9,9 @@ centralizes the policy:
 * ``None`` autodetects: the Pallas path turns on when the default JAX
   backend is a TPU, and interpret mode turns on everywhere else, so the
   same call site runs the hand-fused XLA reference on CPU hosts and the
-  Mosaic-lowered kernel on real hardware.
+  Mosaic-lowered kernel on real hardware;
+* below this layer nothing defaults: every ``*_pallas`` kernel entry
+  takes ``interpret`` as a required keyword.
 """
 
 from __future__ import annotations
@@ -28,22 +30,16 @@ class PallasUnsupportedError(RuntimeError):
 @functools.lru_cache(maxsize=1)
 def tpu_available() -> bool:
     """True when the default JAX backend is a TPU (cached: the device
-    set is fixed for the process lifetime)."""
-    import jax
-
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    set is fixed for the process lifetime).  A backend that fails to
+    initialise raises here: a TPU host whose chip is unusable must not
+    quietly run its kernels in interpret mode on the CPU."""
+    return _platform() == "tpu"
 
 
 def _platform() -> str:
     import jax
 
-    try:
-        return jax.devices()[0].platform
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].platform
 
 
 def resolve(use_pallas: Optional[bool] = None,

@@ -31,7 +31,7 @@ def _hash_kernel(codes_ref, out_ref, *, dim: int, salt: int):
                                              "interpret"))
 def feature_hash_pallas(codes: jnp.ndarray, dim: int,
                         salt: int = 0x9E3779B9, bn: int = DEFAULT_BN,
-                        interpret: bool = True) -> jnp.ndarray:
+                        *, interpret: bool) -> jnp.ndarray:
     orig_shape = codes.shape
     flat = codes.reshape(-1)
     n = flat.shape[0]

@@ -76,7 +76,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, m_ref, l_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("bb", "bs", "interpret"))
 def decode_partials_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                            lengths: jnp.ndarray, bb: int = DEFAULT_BB,
-                           bs: int = DEFAULT_BS, interpret: bool = True):
+                           bs: int = DEFAULT_BS, *, interpret: bool):
     """q: (N, D) flattened batch*heads; k/v: (N, S, D);
     lengths: (N,) live KV length per row.  Returns (m (N,), l (N,),
     o (N, D)) partial-softmax states."""

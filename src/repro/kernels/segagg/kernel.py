@@ -56,7 +56,7 @@ def _segagg_kernel(segs_ref, values_ref, out_ref, *, bs: int):
                    static_argnames=("n_segments", "bn", "bs", "interpret"))
 def segagg_pallas(values: jnp.ndarray, seg_ids: jnp.ndarray,
                   n_segments: int, bn: int = DEFAULT_BN,
-                  bs: int = DEFAULT_BS, interpret: bool = True
+                  bs: int = DEFAULT_BS, *, interpret: bool
                   ) -> jnp.ndarray:
     n, f = values.shape
     bn = min(bn, _ceil_mult(n, 8))

@@ -110,12 +110,13 @@ def _unstack_batched(plan, folded_groups: Sequence[jnp.ndarray]
 def _run_pallas(plan, ident_list, data_list: List[jnp.ndarray],
                 ts: jnp.ndarray, queries: jnp.ndarray, r_real: int,
                 interpret: bool) -> List[Dict[str, jnp.ndarray]]:
-    """Pad (U, rp, F) lane blocks to a pow2 row count if needed and run
-    the lane-tiled Pallas kernel.  ``data_list`` rows beyond ``r_real``
-    must already be identity/INT_MAX (the offline blocks satisfy this by
-    construction; the batched online path pads here)."""
+    """Pad (U, rp, F) lane blocks to a pow2 row count (at least one
+    8-row sublane tile) if needed and run the lane-tiled Pallas kernel.
+    ``data_list`` rows beyond ``r_real`` must already be identity/INT_MAX
+    (the offline blocks satisfy this by construction; the batched online
+    path pads here)."""
     u, r = ts.shape
-    rp = max(2, _next_pow2(r))
+    rp = max(8, _next_pow2(r))
     if rp > r:
         padded = []
         for grp, iv, data in zip(plan.groups, ident_list, data_list):

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import shard_map_compat as _shard_map
-
 _NEG = -1e30
 
 
@@ -103,7 +101,7 @@ def sharded_decode_attention(q, cache_k, cache_v, k_new, v_new, pos,
 
     cache_spec = P(bspec, axis, None, None)
     rep = P(bspec, None, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(rep, cache_spec, cache_spec, rep, rep, P(bspec)),
         out_specs=(rep, cache_spec, cache_spec))

@@ -424,11 +424,7 @@ class FeatureEngine:
         if not rows:
             return []
         t0 = time.perf_counter()
-        enc = [self._encode_request(r) for r in rows]
-        keys = [e[0] for e in enc]
-        ts = [e[1] for e in enc]
-        values = {c: [e[2][c] for e in enc]
-                  for c in self._need[self.cs.script.base_table]}
+        keys, ts, values = self._encode_batch(rows)
         store = self.store if snapshot is None else snapshot.store
         pre = (self.pre_states if snapshot is None
                else snapshot.pre_states)
@@ -436,8 +432,7 @@ class FeatureEngine:
             feats = self.cs.online_sharded_batch(
                 store, keys, ts, values,
                 preagg_states=pre if self.use_preagg else None)
-        elif (not self.use_preagg
-              and getattr(self.cs.ctx, "fused_unit_fold", False)):
+        elif self._serves_fused():
             # fused scripts serve batches through the megakernel fast
             # path (bitwise equal to online_batch; one unit_fold
             # dispatch per window group, warm executable per pad class)
@@ -456,6 +451,20 @@ class FeatureEngine:
         self.latencies_ms.extend([dt_ms] * len(rows))
         return [{k: v[i] for k, v in feats.items()}
                 for i in range(len(rows))]
+
+    def fused_serving_hlo(self, rows: Sequence[Dict[str, Any]]) -> str:
+        """Compiled HLO text of the executable ``request_batch(rows)``
+        runs on a ``fused_fold=True`` engine — where a compiled unit-fold
+        kernel shows as a ``tpu_custom_call``."""
+        if not self._serves_fused():
+            raise ValueError("request_batch does not serve this engine "
+                             "through the fused step")
+        return self.cs.online_batch_fast_hlo(self.store,
+                                             *self._encode_batch(rows))
+
+    def _serves_fused(self) -> bool:
+        return (not self.sharded and not self.use_preagg
+                and getattr(self.cs.ctx, "fused_unit_fold", False))
 
     def snapshot(self) -> EngineSnapshot:
         """Cut an immutable view of (store, pre-agg states) for the
@@ -637,6 +646,12 @@ class FeatureEngine:
         values = {c: float(self._encode(base, c, row[c]))
                   for c in self._need[base]}
         return key, ts, values
+
+    def _encode_batch(self, rows: Sequence[Dict[str, Any]]):
+        enc = [self._encode_request(r) for r in rows]
+        values = {c: [e[2][c] for e in enc]
+                  for c in self._need[self.cs.script.base_table]}
+        return [e[0] for e in enc], [e[1] for e in enc], values
 
     def _encode(self, table: str, col: str, v):
         d = self.dicts.get(table, {}).get(col)

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..distributed.sharding import vary_like
+
 INT_MAX = np.int32(2**31 - 1)
 
 __all__ = ["StoreState", "OnlineStore", "ShardedOnlineStore",
@@ -70,8 +72,8 @@ def _bsearch(keys: jnp.ndarray, tss: jnp.ndarray, key, ts,
     never a scan."""
     n = keys.shape[0]
     steps = max(1, (n - 1).bit_length() + 1)
-    lo = jnp.int32(0)
-    hi = jnp.int32(n)
+    lo = vary_like(jnp.int32(0), keys, tss, key, ts)
+    hi = vary_like(jnp.int32(n), keys, tss, key, ts)
 
     def body(_, carry):
         lo_, hi_ = carry
@@ -130,32 +132,47 @@ def insert_many(state: StoreState, keys, ts, values: Dict[str, jnp.ndarray],
     """Sorted insert of a padded batch of rows with ONE merge.
 
     ``keys``/``ts`` are (M,) int32 with padding rows carrying INT_MAX in
-    both; ``n_new`` is the number of real rows.  Cost is one
-    O((capacity+M) log) lexsort instead of M O(capacity) suffix shifts —
-    the bulk-ingest analogue of the skiplist's batch build.
+    both; ``n_new`` is the number of real rows.  Only the batch is
+    sorted; it is merged into the already-sorted store by binary search
+    (O(M log capacity)) and one O(capacity) gather — the bulk-ingest
+    analogue of the skiplist's batch build.  No sort spans the store:
+    XLA's TPU sort takes minutes to compile at 2**20 rows, and every
+    follower device compiles its own copy.
 
     Ordering matches M sequential ``insert`` calls: new rows land *after*
-    existing peers with equal (key, ts) (existing rows carry smaller
-    arrival ranks), and arrival order among the new rows themselves is
-    preserved (rank = capacity + j).  Rows sorted beyond ``capacity`` are
+    existing peers with equal (key, ts), and arrival order among the new
+    rows themselves is preserved.  Rows merged beyond ``capacity`` are
     dropped — the host wrapper guarantees they are padding only.
     """
     cap = state["keys"].shape[0]
     m = keys.shape[0]
-    rank = jnp.concatenate([jnp.arange(cap, dtype=jnp.int32),
-                            cap + jnp.arange(m, dtype=jnp.int32)])
-    all_keys = jnp.concatenate([state["keys"], jnp.asarray(keys, jnp.int32)])
-    all_ts = jnp.concatenate([state["ts"], jnp.asarray(ts, jnp.int32)])
-    perm = jnp.lexsort((rank, all_ts, all_keys))[:cap]
+    order = jnp.arange(m, dtype=jnp.int32)
+    k_new, t_new, order = jax.lax.sort(
+        (jnp.asarray(keys, jnp.int32), jnp.asarray(ts, jnp.int32), order),
+        num_keys=3)
+    at = jax.vmap(lambda k, t: _bsearch(state["keys"], state["ts"], k, t,
+                                        strict=True))(k_new, t_new)
+    # slot of the r-th sorted new row (ascending); the old rows fill the
+    # other slots in order.  A binary search per slot, not a prefix sum:
+    # a 2**20-row cumsum alone takes ~20 s to compile for a TPU.
+    dest = at + jnp.arange(m, dtype=jnp.int32)
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    n_upto = jnp.searchsorted(dest, slot, side="right").astype(jnp.int32)
+    src_new = jnp.clip(n_upto - 1, 0, m - 1)
+    src_old = slot - n_upto
+    take_new = (n_upto > 0) & (dest[src_new] == slot)
+
+    def merge(old, new):
+        return jnp.where(take_new, new[src_new], old[src_old])
 
     new_cols = {}
     for name, arr in state["cols"].items():
         v = jnp.asarray(values.get(name, jnp.zeros((m,), arr.dtype)),
                         arr.dtype)
-        new_cols[name] = jnp.take(jnp.concatenate([arr, v]), perm, axis=0)
+        new_cols[name] = merge(arr, v[order])
     return {
-        "keys": jnp.take(all_keys, perm),
-        "ts": jnp.take(all_ts, perm),
+        "keys": merge(state["keys"], k_new),
+        "ts": merge(state["ts"], t_new),
         "cols": new_cols,
         "count": state["count"] + jnp.asarray(n_new, jnp.int32),
     }

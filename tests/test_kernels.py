@@ -22,6 +22,8 @@ from repro.kernels.feature_hash import ops as hash_ops
 from repro.kernels.flash_decode import ops as fd_ops
 from repro.kernels.unit_fold import ops as uf_ops
 
+from examples.leaf_families import SQL as UNIT_SQL
+
 
 # ------------------------------------------------------------------ segagg
 
@@ -173,31 +175,6 @@ def test_flash_decode_shard_merge_is_exact():
 
 # --------------------------------------------------- unit_fold megakernel
 
-UNIT_SQL = """
-SELECT
-  sum(price) OVER w3s AS s_price,
-  avg(price) OVER w3s AS a_price,
-  count(price) OVER w3s AS c_price,
-  min(price) OVER w3s AS mn_price,
-  max(price) OVER w3s AS mx_price,
-  distinct_count(item) OVER w3s AS dc_item,
-  topn_frequency(item, 3) OVER w3s AS topn_item,
-  avg_cate_where(price, item, price > 1.0) OVER w3s AS acw,
-  drawdown(price) OVER wr AS dd_price,
-  ew_avg(price, 0.5) OVER wr AS ew_price,
-  sum(price) OVER wx AS s_price_x,
-  min(price) OVER wm AS mn_price_m
-FROM actions
-WINDOW w3s AS (PARTITION BY uid ORDER BY ts
-               ROWS_RANGE BETWEEN 3s PRECEDING AND CURRENT ROW),
-  wr AS (PARTITION BY uid ORDER BY ts
-         ROWS BETWEEN 50 PRECEDING AND CURRENT ROW),
-  wx AS (PARTITION BY uid ORDER BY ts
-         ROWS_RANGE BETWEEN 5s PRECEDING AND CURRENT ROW
-         MAXSIZE 7 EXCLUDE CURRENT_ROW),
-  wm AS (PARTITION BY uid ORDER BY ts
-         ROWS BETWEEN 10 PRECEDING AND CURRENT ROW MAXSIZE 4)
-"""
 
 
 @pytest.fixture(scope="module")
@@ -466,6 +443,44 @@ def test_dispatch_forced_interpret_runs_off_tpu():
     assert dispatch.resolve(True, None)[0] is True   # interpret follows
     if not dispatch.tpu_available():
         assert dispatch.resolve(True, None)[1] is True
+
+
+def test_dispatch_backend_init_error_propagates(monkeypatch):
+    """A backend that fails to initialise is an error, not "no TPU":
+    autodetect must not quietly fall back to interpret mode."""
+    from repro.kernels import dispatch
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    dispatch.tpu_available.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="initialize backend"):
+            dispatch.resolve(None, None)
+    finally:
+        dispatch.tpu_available.cache_clear()
+
+
+def test_kernel_entries_have_no_interpret_default():
+    """Every ``*_pallas`` entry takes ``interpret`` as a required
+    keyword: only ``dispatch.resolve`` picks a default."""
+    import inspect
+
+    from repro.kernels.batch_windowfold.kernel import \
+        batch_windowfold_pallas
+    from repro.kernels.chunked_scan.kernel import linear_scan_pallas
+    from repro.kernels.feature_hash.kernel import feature_hash_pallas
+    from repro.kernels.flash_decode.kernel import decode_partials_pallas
+    from repro.kernels.segagg.kernel import segagg_pallas
+    from repro.kernels.unit_fold.kernel import unit_fold_pallas
+
+    for fn in (batch_windowfold_pallas, linear_scan_pallas,
+               feature_hash_pallas, decode_partials_pallas, segagg_pallas,
+               unit_fold_pallas):
+        p = inspect.signature(inspect.unwrap(fn)).parameters["interpret"]
+        assert p.kind is p.KEYWORD_ONLY, fn
+        assert p.default is p.empty, fn
 
 
 def test_dispatch_compiled_pallas_off_tpu_raises_typed_error(unit_case):

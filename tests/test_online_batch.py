@@ -113,8 +113,11 @@ def test_online_batch_with_last_join(action_tables):
 # ------------------------------------------------------- bulk store ingest
 
 
-def test_put_many_equals_sequential_put():
-    rng = np.random.default_rng(0)
+@pytest.mark.parametrize("seed,n_new", [(0, 13), (1, 1), (2, 32), (3, 54)])
+def test_put_many_equals_sequential_put(seed, n_new):
+    """One merged batch == n_new sorted single inserts, ties (same key
+    and ts) in arrival order, a full store included (10 + 54 = 64)."""
+    rng = np.random.default_rng(seed)
     cap = 64
     s1 = timestore.OnlineStore(cap)
     s2 = timestore.OnlineStore(cap)
@@ -125,11 +128,11 @@ def test_put_many_equals_sequential_put():
                   {"v": float(i), "c": i})
     # same seed history for both stores
     s2.tables["t"] = s1.tables["t"]
-    keys = rng.integers(0, 4, size=13).astype(np.int32)
-    ts = rng.integers(0, 50, size=13).astype(np.int32)
-    cols = {"v": rng.normal(size=13).astype(np.float32),
-            "c": np.arange(13, dtype=np.int32)}
-    for i in range(13):
+    keys = rng.integers(0, 4, size=n_new).astype(np.int32)
+    ts = rng.integers(0, 50, size=n_new).astype(np.int32)
+    cols = {"v": rng.normal(size=n_new).astype(np.float32),
+            "c": np.arange(n_new, dtype=np.int32)}
+    for i in range(n_new):
         s1.put("t", int(keys[i]), int(ts[i]),
                {"v": float(cols["v"][i]), "c": int(cols["c"][i])})
     off = s2.put_many("t", keys, ts, cols)
@@ -141,8 +144,8 @@ def test_put_many_equals_sequential_put():
         np.testing.assert_array_equal(
             np.asarray(s1.tables["t"]["cols"][c]),
             np.asarray(s2.tables["t"]["cols"][c]))
-    assert s1.n_rows("t") == s2.n_rows("t") == 23
-    assert s2._binlog_offset == 23
+    assert s1.n_rows("t") == s2.n_rows("t") == 10 + n_new
+    assert s2._binlog_offset == 10 + n_new
 
 
 def test_put_many_overflow_and_empty():
