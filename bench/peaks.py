@@ -1,0 +1,19 @@
+"""Published peaks per chip, keyed by JAX's ``device_kind``.
+
+TPU v5e: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s.  A kind that
+is not here is an error, never a default.
+"""
+
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops_per_s": 197e12, "int8_ops_per_s": 393e12,
+                    "hbm_bytes_per_s": 819e9, "hbm_bytes": 16e9},
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
