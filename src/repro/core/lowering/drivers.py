@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...spans import span
 from ...storage import timestore
 from .. import skew
 
@@ -114,17 +115,24 @@ def plan_offline(cs, tables) -> Tuple[List[GroupLowering],
         cache = cs._offline_plan_cache = {}
     # content fingerprint only: a fresh dict with identical tables must
     # hit, an in-place mutation must miss
-    key = _tables_sig(tables)
-    hit = cache.get(key)
+    with span("offline.fingerprint"):
+        key = _tables_sig(tables)
+        hit = cache.get(key)
     if hit is not None:
         return hit
-    arrays = _np_arrays(tables)
-    n_base = len(tables[cs.script.base_table])
-    lws = [lower_group_offline(
-        members, arrays, cs.script.base_table, n_base,
-        target_rows=cs.ctx.offline_slice_rows,
-        max_slices=cs.ctx.offline_max_slices)
-        for members in group_windows(cs.windows)]
+    with span("offline.plan"):
+        arrays = _np_arrays(tables)
+        n_base = len(tables[cs.script.base_table])
+        lws = []
+        for members in group_windows(cs.windows):
+            with span("offline.plan.group") as sp:
+                gl = lower_group_offline(
+                    members, arrays, cs.script.base_table, n_base,
+                    target_rows=cs.ctx.offline_slice_rows,
+                    max_slices=cs.ctx.offline_max_slices)
+                if sp.is_enabled():
+                    sp.set_metadata(**gl.fill())
+            lws.append(gl)
     cache.clear()          # keep at most one resident plan per script
     cache[key] = (lws, arrays, n_base)
     return lws, arrays, n_base
@@ -193,6 +201,11 @@ def _plan_sig(cs, lws: Sequence[GroupLowering], arrays) -> Tuple:
 
 def offline_fused(cs, tables) -> Dict[str, np.ndarray]:
     """Default offline schedule: all groups + joins + scalars, one jit."""
+    with span("offline.call"):
+        return _offline_fused(cs, tables)
+
+
+def _offline_fused(cs, tables) -> Dict[str, np.ndarray]:
     lws, arrays, n_base = plan_offline(cs, tables)
     key = ("offline_fused", _plan_sig(cs, lws, arrays))
     # the cached closure must capture only static metadata — closing
@@ -213,12 +226,16 @@ def offline_fused(cs, tables) -> Dict[str, np.ndarray]:
     arrays_dev = {t: {c: jnp.asarray(v) for c, v in cols.items()}
                   for t, cols in arrays.items()}
     branch, flat = fn([gl.device_args() for gl in lws], arrays_dev)
-    out: Dict[str, np.ndarray] = {}
-    for gl, feats in zip(lws, branch):
-        _scatter_group(gl, feats, n_base, out)
-    for name, v in flat.items():
-        out[name] = np.asarray(v)
-    return scalars.select_outputs(cs.script, out)
+    # the copies below would wait for the outputs anyway; waiting here
+    # keeps the device's time out of the scatter span
+    jax.block_until_ready((branch, flat))
+    with span("offline.scatter"):
+        out: Dict[str, np.ndarray] = {}
+        for gl, feats in zip(lws, branch):
+            _scatter_group(gl, feats, n_base, out)
+        for name, v in flat.items():
+            out[name] = np.asarray(v)
+        return scalars.select_outputs(cs.script, out)
 
 
 def offline_branch(cs, tables, wi: int) -> Dict[str, np.ndarray]:
@@ -560,7 +577,8 @@ def online_batch(cs, store, keys, ts, values, preagg_states=None
                  ) -> Dict[str, np.ndarray]:
     """Features for B requests in ONE jitted call (vmapped online
     driver); bit-identical to B scalar ``online`` calls."""
-    keys, tsa, vals_np, b = pad_batch(keys, ts, values)
+    with span("serve.encode"):
+        keys, tsa, vals_np, b = pad_batch(keys, ts, values)
     use_pre = preagg_states is not None
     fn = store_fn(
         cs, store, "online_batch",
@@ -573,7 +591,8 @@ def online_batch(cs, store, keys, ts, values, preagg_states=None
              preagg_states if use_pre else {})
     if use_pre:
         cs._observe_queries(tsa[:b].tolist())
-    return {k: np.asarray(v)[:b] for k, v in out.items()}
+    with span("serve.fetch"):
+        return {k: np.asarray(v)[:b] for k, v in out.items()}
 
 
 def online_sharded_batch(cs, store, keys, ts, values, preagg_states=None
@@ -633,7 +652,8 @@ def online_sharded_batch(cs, store, keys, ts, values, preagg_states=None
              preagg_states if use_pre else {})
     if use_pre:
         cs._observe_queries(tsa.tolist())
-    return {k: np.asarray(v)[owner, slot] for k, v in out.items()}
+    with span("serve.fetch"):
+        return {k: np.asarray(v)[owner, slot] for k, v in out.items()}
 
 
 def _sharded_store_fn(cs, store, use_pre: bool, b_pad: int):
@@ -674,7 +694,8 @@ def online_batch_fast(cs, store, keys, ts, values, use_pallas=None,
     fn, args, b = _fast_step(cs, store, keys, ts, values, use_pallas,
                              interpret)
     out = fn(*args)
-    return {k: np.asarray(v)[:b] for k, v in out.items()}
+    with span("serve.fetch"):
+        return {k: np.asarray(v)[:b] for k, v in out.items()}
 
 
 def online_batch_fast_hlo(cs, store, keys, ts, values, use_pallas=None,
@@ -694,7 +715,8 @@ def _fast_step(cs, store, keys, ts, values, use_pallas, interpret):
     from ...kernels import dispatch
     use_pallas, interpret = dispatch.resolve(use_pallas, interpret,
                                              flag="unit_fold_pallas")
-    keys, tsa, vals_np, b = pad_batch(keys, ts, values)
+    with span("serve.encode"):
+        keys, tsa, vals_np, b = pad_batch(keys, ts, values)
     # keys/ts/values are fresh per-call device buffers the caller never
     # reads back — donating them lets XLA alias the (B, R) gather
     # scratch onto them instead of allocating per request batch.  The

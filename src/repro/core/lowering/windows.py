@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...spans import span
 from ...storage import timestore
 from ..expr import ColumnRef, collect_columns
 from ..functions import Aggregator, Leaf, build_aggregator
@@ -215,6 +216,12 @@ class GroupLowering:
                 tuple(b.idx.shape for b in self.blocks),
                 self.ts.shape[0], tuple(sorted(self.cols)))
 
+    def fill(self) -> Dict[str, int]:
+        """``rows``, the real rows the units fold, and ``slots``, the
+        padded unit slots the device folds."""
+        return {"rows": int(sum(b.sizes.sum() for b in self.blocks)),
+                "slots": sum(b.idx.size for b in self.blocks)}
+
     def device_args(self) -> Dict[str, Any]:
         """Device copies of the plan arrays (cached: repeated offline
         calls over the same tables re-use resident buffers, mirroring
@@ -261,32 +268,33 @@ def lower_group_offline(members: Sequence[LoweredWindow],
         set().union(*(m.needed_cols for m in members)) -
         {spec.partition_by, spec.order_by})
 
-    key_p, ts_p, rank_p, arr_p, orig_p = [], [], [], [], []
-    col_p: Dict[str, List[np.ndarray]] = {c: [] for c in cols_needed}
-    for rank, tname in enumerate(w.sources):
-        cols = arrays[tname]
-        n_t = next(iter(cols.values())).shape[0]
-        is_base = tname == base_table and rank == len(w.sources) - 1
-        key_p.append(np.asarray(cols[spec.partition_by], np.int64))
-        ts_p.append(np.asarray(cols[spec.order_by], np.int64))
-        rank_p.append(np.full((n_t,), rank, np.int64))
-        arr_p.append(np.arange(n_t, dtype=np.int64))
-        orig_p.append(np.arange(n_t, dtype=np.int32) if is_base
-                      else np.full((n_t,), n_base, np.int32))
-        for c in cols_needed:
-            col_p[c].append(np.asarray(cols[c]))
+    with span("offline.plan.sort"):
+        key_p, ts_p, rank_p, arr_p, orig_p = [], [], [], [], []
+        col_p: Dict[str, List[np.ndarray]] = {c: [] for c in cols_needed}
+        for rank, tname in enumerate(w.sources):
+            cols = arrays[tname]
+            n_t = next(iter(cols.values())).shape[0]
+            is_base = tname == base_table and rank == len(w.sources) - 1
+            key_p.append(np.asarray(cols[spec.partition_by], np.int64))
+            ts_p.append(np.asarray(cols[spec.order_by], np.int64))
+            rank_p.append(np.full((n_t,), rank, np.int64))
+            arr_p.append(np.arange(n_t, dtype=np.int64))
+            orig_p.append(np.arange(n_t, dtype=np.int32) if is_base
+                          else np.full((n_t,), n_base, np.int32))
+            for c in cols_needed:
+                col_p[c].append(np.asarray(cols[c]))
 
-    key = np.concatenate(key_p)
-    ts = np.concatenate(ts_p)
-    rank = np.concatenate(rank_p)
-    arrival = np.concatenate(arr_p)
-    orig = np.concatenate(orig_p)
-    perm = np.lexsort((arrival, rank, ts, key))
+        key = np.concatenate(key_p)
+        ts = np.concatenate(ts_p)
+        rank = np.concatenate(rank_p)
+        arrival = np.concatenate(arr_p)
+        orig = np.concatenate(orig_p)
+        perm = np.lexsort((arrival, rank, ts, key))
 
-    key_s = key[perm]
-    ts_s = ts[perm].astype(np.int32)
-    orig_s = orig[perm]
-    cols_s = {c: np.concatenate(col_p[c])[perm] for c in cols_needed}
+        key_s = key[perm]
+        ts_s = ts[perm].astype(np.int32)
+        orig_s = orig[perm]
+        cols_s = {c: np.concatenate(col_p[c])[perm] for c in cols_needed}
 
     units = skew.plan_window_units(
         key_s, ts_s,

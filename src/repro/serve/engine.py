@@ -80,6 +80,7 @@ import numpy as np
 from ..core.compiler import CompiledScript, compile_script
 from ..core.types import Table
 from ..distributed.fault import CheckpointManager
+from ..spans import span
 from ..storage.memest import MemoryGuard
 from ..storage.replication import (FailoverController, PromotionRecord,
                                    ReplicationManager,
@@ -363,32 +364,33 @@ class FeatureEngine:
         if not rows:
             return
         t0 = time.perf_counter()
-        kc = self._key_col()
-        keys = np.asarray([self._encode(table, kc, r[kc]) for r in rows],
-                          np.int32)
-        ts = np.asarray([int(r[self.cs.script.order_column])
-                         for r in rows], np.int32)
-        cols = {c: np.asarray([float(self._encode(table, c, r[c]))
-                               for r in rows], np.float32)
-                for c in self._need[table]}
-        nbytes = len(rows) * (64 + 8 * len(cols))
-        self.guard.charge(nbytes)
-        try:
-            self.store.put_many(table, keys, ts, cols)
-        except Exception:
-            self.guard.release(nbytes)   # nothing was stored
-            raise
-        if self.use_preagg:
-            if self.sharded:
-                self.pre_states = self.cs.preagg_update_many_sharded(
-                    self.pre_states, table, keys, ts, cols,
-                    self._preagg_owned())
-            else:
-                self.pre_states = self.cs.preagg_update_many(
-                    self.pre_states, table, keys, ts, cols)
-        if self.ttl_ms:
-            self._evict_release(table, int(ts.max()) - self.ttl_ms)
-        self._after_ingest(table, len(rows), int(ts.max()))
+        with span("ingest.apply"):
+            kc = self._key_col()
+            keys = np.asarray([self._encode(table, kc, r[kc])
+                               for r in rows], np.int32)
+            ts = np.asarray([int(r[self.cs.script.order_column])
+                             for r in rows], np.int32)
+            cols = {c: np.asarray([float(self._encode(table, c, r[c]))
+                                   for r in rows], np.float32)
+                    for c in self._need[table]}
+            nbytes = len(rows) * (64 + 8 * len(cols))
+            self.guard.charge(nbytes)
+            try:
+                self.store.put_many(table, keys, ts, cols)
+            except Exception:
+                self.guard.release(nbytes)   # nothing was stored
+                raise
+            if self.use_preagg:
+                if self.sharded:
+                    self.pre_states = self.cs.preagg_update_many_sharded(
+                        self.pre_states, table, keys, ts, cols,
+                        self._preagg_owned())
+                else:
+                    self.pre_states = self.cs.preagg_update_many(
+                        self.pre_states, table, keys, ts, cols)
+            if self.ttl_ms:
+                self._evict_release(table, int(ts.max()) - self.ttl_ms)
+            self._after_ingest(table, len(rows), int(ts.max()))
         # write-path timing is tracked apart from request latencies:
         # one amortized batch write must never appear as N cheap
         # "request" samples and deflate the served percentiles
@@ -424,7 +426,8 @@ class FeatureEngine:
         if not rows:
             return []
         t0 = time.perf_counter()
-        keys, ts, values = self._encode_batch(rows)
+        with span("serve.encode"):
+            keys, ts, values = self._encode_batch(rows)
         store = self.store if snapshot is None else snapshot.store
         pre = (self.pre_states if snapshot is None
                else snapshot.pre_states)

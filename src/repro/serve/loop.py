@@ -58,6 +58,7 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, \
 
 import numpy as np
 
+from ..spans import span
 from .batcher import RequestBatcher
 from .clock import Clock, SystemClock, VirtualClock
 from .engine import FeatureEngine
@@ -132,7 +133,10 @@ class ServeLoop:
         self._submit_t: Dict[int, float] = {}
         self._deadline_at: Dict[int, float] = {}
         self.results: Dict[int, Dict[str, np.ndarray]] = {}
-        self.latencies: List[float] = []
+        # bounded like the engine's own samples: percentiles are over the
+        # most recent window, not the life of the loop
+        self.latencies: Deque[float] = collections.deque(
+            maxlen=engine.latencies_ms.maxlen)
         self.stats = {"accepted": 0, "shed": 0, "served": 0,
                       "size_flushes": 0, "deadline_flushes": 0,
                       "forced_flushes": 0, "deadline_misses": 0,
@@ -274,8 +278,9 @@ class ServeLoop:
             key = "size_flushes" if size_flush else "deadline_flushes"
             self.stats[key] += 1
         t0 = time.perf_counter()
-        feats = self.engine.request_batch(payloads[:n_real],
-                                          snapshot=self.snap)
+        with span("serve.flush"):
+            feats = self.engine.request_batch(payloads[:n_real],
+                                              snapshot=self.snap)
         svc_ms = (self.service_model(n_real) if self.service_model
                   is not None else (time.perf_counter() - t0) * 1e3)
         if self.service_model is not None and \

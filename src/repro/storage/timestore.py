@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.sharding import vary_like
+from ..spans import span
 
 INT_MAX = np.int32(2**31 - 1)
 
@@ -466,7 +467,10 @@ class OnlineStore(_BinlogMixin):
         n = keys.shape[0]
         if n == 0:
             return self._binlog_offset
-        if self.n_rows(table) + n > self.capacity:
+        # the live row count waits for the merges dispatched before it
+        with span("ingest.wait"):
+            live = self.n_rows(table)
+        if live + n > self.capacity:
             raise ValueError(f"bulk put of {n} rows overflows capacity "
                              f"{self.capacity}")
         m = next_pow2(n)
@@ -634,7 +638,9 @@ class ShardedOnlineStore(_BinlogMixin):
         slots = self.route_slots(keys)
         owner = self.assignment[slots]
         counts = np.bincount(owner, minlength=self.n_shards)
-        live = self.n_rows_per_shard(table)
+        # the live row counts wait for the merges dispatched before them
+        with span("ingest.wait"):
+            live = self.n_rows_per_shard(table)
         over = np.flatnonzero(live + counts > self.capacity)
         if over.size:
             s = int(over[0])

@@ -400,6 +400,23 @@ def test_latency_percentile_edge_cases(loop_tables):
     assert loop.latency_percentiles() == {}       # loop: same contract
 
 
+def test_loop_latencies_bounded_like_the_engine(loop_tables):
+    """The loop keeps the same number of recent samples as its engine,
+    not one per request for the life of the loop."""
+    eng = FeatureEngine(RAW_SQL, loop_tables, capacity=512,
+                        latency_window=8)
+    loop = ServeLoop(eng, clock=VirtualClock(), batch_size=4)
+    a = loop_tables["actions"]
+    for rnd in range(3):
+        for i in range(4):
+            loop.submit(dict(a.row(4 * rnd + i)))
+        loop.flush()
+    assert loop.stats["served"] == 12
+    assert len(loop.latencies) == 8
+    assert set(loop.latency_percentiles()) == {"TP50", "TP99", "TP999",
+                                               "max_ms"}
+
+
 def test_fused_loop_no_retrace_across_snapshot_swaps(loop_tables):
     """Executable reuse (ISSUE 9 tentpole c): under ServeLoop traffic a
     fused engine serves every batch through the megakernel fast path,
