@@ -17,6 +17,9 @@ All operations are pure jax (jit-able, static shapes):
                      suffix — fully parallel on a vector unit, unlike a CAS
                      chain, and single-writer per shard matches the paper's
                      replicator-lock serialization anyway),
+  * ``insert_many``  a sorted M-row batch merged by M binary searches and
+                     a few dozen streaming passes of static shifts: no
+                     gather, sort or loop spans the store,
   * ``range_bounds`` O(log capacity) via branchless binary search,
   * ``evict_before`` batch TTL deletion (§7.2): drop every row with
                      ts < horizon in one compaction pass,
@@ -64,7 +67,7 @@ def make_state(capacity: int, col_specs: Dict[str, jnp.dtype]) -> StoreState:
 
 
 def _bsearch(keys: jnp.ndarray, tss: jnp.ndarray, key, ts,
-             strict: bool) -> jnp.ndarray:
+             strict: bool, unroll: bool = False) -> jnp.ndarray:
     """Branchless binary search over the (key, ts)-sorted store:
     first index i with (keys[i], ts[i]) > (key, ts)   [strict=True]
     or >= (key, ts)                                    [strict=False].
@@ -91,7 +94,7 @@ def _bsearch(keys: jnp.ndarray, tss: jnp.ndarray, key, ts,
         lo_ = jnp.where(go_left | (lo_ >= hi_), lo_, mid + 1)
         return lo_, hi_
 
-    lo, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
+    lo, _ = jax.lax.fori_loop(0, steps, body, (lo, hi), unroll=unroll)
     return lo.astype(jnp.int32)
 
 
@@ -127,6 +130,26 @@ def insert(state: StoreState, key, ts, values: Dict[str, jnp.ndarray]
     }
 
 
+def _shift(x: jnp.ndarray, s: int) -> jnp.ndarray:
+    """``x`` moved ``s`` slots toward its end: the first ``s`` slots read
+    zero and the last ``s`` values fall off (a static pad, no gather)."""
+    n = x.shape[0]
+    if s >= n:
+        return jnp.zeros_like(x)
+    return jax.lax.pad(x, jnp.zeros((), x.dtype), [(s, -s, 0)])
+
+
+def _prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum of an int32 array in log2(n) passes of a
+    static shift and an add.  ``jnp.cumsum`` is exact too, but a 2**20-row
+    one takes ~20 s to compile for a TPU."""
+    s = 1
+    while s < x.shape[0]:
+        x = x + _shift(x, s)
+        s *= 2
+    return x
+
+
 @jax.jit
 def insert_many(state: StoreState, keys, ts, values: Dict[str, jnp.ndarray],
                 n_new) -> StoreState:
@@ -134,11 +157,26 @@ def insert_many(state: StoreState, keys, ts, values: Dict[str, jnp.ndarray],
 
     ``keys``/``ts`` are (M,) int32 with padding rows carrying INT_MAX in
     both; ``n_new`` is the number of real rows.  Only the batch is
-    sorted; it is merged into the already-sorted store by binary search
-    (O(M log capacity)) and one O(capacity) gather — the bulk-ingest
-    analogue of the skiplist's batch build.  No sort spans the store:
-    XLA's TPU sort takes minutes to compile at 2**20 rows, and every
-    follower device compiles its own copy.
+    sorted; it is merged into the already-sorted store without a sort,
+    a gather or a loop over the store — the bulk-ingest analogue of the
+    skiplist's batch build:
+
+    1. M binary searches (O(M log capacity)) give ``at[r]``, the first
+       old row after sorted new row r;
+    2. old row j moves by ``c[j] = #{r : at[r] <= j}``: M ones
+       scatter-added at ``at``, then a prefix sum of log2(capacity)
+       static shifts;
+    3. the old rows move by ``c`` in bit_length(M) rounds, highest bit
+       first: round b shifts every array by the static ``2**b`` and
+       selects.  ``c`` never decreases, so ``c`` with its bits below b
+       cleared keeps positions strictly increasing: no two rows meet;
+    4. the M new rows are scattered to ``at[r] + r``, the slots the old
+       rows left.
+
+    So an apply costs O(M log capacity) plus about log2(capacity) +
+    bit_length(M) streaming passes over the store.  No sort spans the
+    store: XLA's TPU sort takes minutes to compile at 2**20 rows, and
+    every follower device compiles its own copy.
 
     Ordering matches M sequential ``insert`` calls: new rows land *after*
     existing peers with equal (key, ts), and arrival order among the new
@@ -152,28 +190,36 @@ def insert_many(state: StoreState, keys, ts, values: Dict[str, jnp.ndarray],
         (jnp.asarray(keys, jnp.int32), jnp.asarray(ts, jnp.int32), order),
         num_keys=3)
     at = jax.vmap(lambda k, t: _bsearch(state["keys"], state["ts"], k, t,
-                                        strict=True))(k_new, t_new)
-    # slot of the r-th sorted new row (ascending); the old rows fill the
-    # other slots in order.  A binary search per slot, not a prefix sum:
-    # a 2**20-row cumsum alone takes ~20 s to compile for a TPU.
+                                        strict=True, unroll=True))(k_new,
+                                                                   t_new)
+    # padding rows search to ``cap``: they add no shift and are dropped
+    shift = _prefix_sum(jnp.zeros((cap,), jnp.int32).at[at].add(
+        1, mode="drop", indices_are_sorted=True))
+    old = [state["keys"], state["ts"], *state["cols"].values()]
+    for b in reversed(range(m.bit_length())):
+        s = 1 << b
+        moved = _shift(shift, s)
+        arrive = (moved & s) != 0
+        old = [jnp.where(arrive, _shift(x, s), x) for x in old]
+        # a slot whose row left and that nothing reached is a hole for a
+        # new row: zero its shift so the stale copy never moves again
+        shift = jnp.where(arrive, moved, jnp.where((shift & s) != 0, 0,
+                                                   shift))
     dest = at + jnp.arange(m, dtype=jnp.int32)
-    slot = jnp.arange(cap, dtype=jnp.int32)
-    n_upto = jnp.searchsorted(dest, slot, side="right").astype(jnp.int32)
-    src_new = jnp.clip(n_upto - 1, 0, m - 1)
-    src_old = slot - n_upto
-    take_new = (n_upto > 0) & (dest[src_new] == slot)
 
-    def merge(old, new):
-        return jnp.where(take_new, new[src_new], old[src_old])
+    def place(arr, new):
+        return arr.at[dest].set(new, mode="drop", indices_are_sorted=True,
+                                unique_indices=True)
 
+    keys_out, ts_out, *cols_out = old
     new_cols = {}
-    for name, arr in state["cols"].items():
+    for (name, arr), merged in zip(state["cols"].items(), cols_out):
         v = jnp.asarray(values.get(name, jnp.zeros((m,), arr.dtype)),
                         arr.dtype)
-        new_cols[name] = merge(arr, v[order])
+        new_cols[name] = place(merged, v[order])
     return {
-        "keys": merge(state["keys"], k_new),
-        "ts": merge(state["ts"], t_new),
+        "keys": place(keys_out, k_new),
+        "ts": place(ts_out, t_new),
         "cols": new_cols,
         "count": state["count"] + jnp.asarray(n_new, jnp.int32),
     }

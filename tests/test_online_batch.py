@@ -1,6 +1,7 @@
 """Batched online execution: vmapped request path, bulk store ingest,
 batched pre-agg maintenance, and the fused unit-fold megakernel."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -146,6 +147,102 @@ def test_put_many_equals_sequential_put(seed, n_new):
             np.asarray(s2.tables["t"]["cols"][c]))
     assert s1.n_rows("t") == s2.n_rows("t") == 10 + n_new
     assert s2._binlog_offset == 10 + n_new
+
+
+def _lexsort_merge(state, keys, ts, values):
+    """Plain NumPy reference of ``insert_many``: the live rows and the
+    batch's real rows ordered by (key, ts, old before new, arrival),
+    cut to capacity; the rest padding (INT_MAX keys and ts, zeros)."""
+    cap, live = state["keys"].shape[0], int(state["count"])
+    real = np.asarray(keys) != timestore.INT_MAX
+    n = int(real.sum())
+    k = np.concatenate([np.asarray(state["keys"])[:live], keys[real]])
+    t = np.concatenate([np.asarray(state["ts"])[:live], ts[real]])
+    new = np.repeat([0, 1], [live, n])
+    arrival = np.concatenate([np.arange(live), np.arange(n)])
+    o = np.lexsort((arrival, new, t, k))[:cap]
+    out = {"keys": np.full(cap, timestore.INT_MAX, np.int32),
+           "ts": np.full(cap, timestore.INT_MAX, np.int32), "cols": {},
+           "count": live + n}
+    out["keys"][:o.size], out["ts"][:o.size] = k[o], t[o]
+    for c, v in values.items():
+        col = np.zeros(cap, v.dtype)
+        col[:o.size] = np.concatenate(
+            [np.asarray(state["cols"][c])[:live], v[real]])[o]
+        out["cols"][c] = col
+    return out
+
+
+def _assert_store_bitwise(got, want):
+    """Whole arrays, padding slots included; floats by their bits."""
+    for f in ("keys", "ts"):
+        np.testing.assert_array_equal(np.asarray(got[f]), want[f], f)
+    for c, v in want["cols"].items():
+        np.testing.assert_array_equal(
+            np.asarray(got["cols"][c]).view(np.int32), v.view(np.int32), c)
+    assert int(got["count"]) == want["count"]
+
+
+def _random_batch(rng, m, n):
+    """An m-row padded batch with n real rows at random slots, ties in
+    (key, ts), and junk in the padding rows' columns (never merged)."""
+    keys = np.full(m, timestore.INT_MAX, np.int32)
+    ts = np.full(m, timestore.INT_MAX, np.int32)
+    slots = rng.permutation(m)[:n]
+    keys[slots] = rng.integers(0, 5, n)
+    ts[slots] = rng.integers(0, 6, n)
+    values = {"v": rng.normal(size=m).astype(np.float32),
+              "c": rng.integers(-9, 9, m).astype(np.int32)}
+    return keys, ts, values
+
+
+@pytest.mark.parametrize("m", [1, 2, 4, 8, 16, 32, 64])
+@pytest.mark.parametrize("cap", [37, 200])
+def test_insert_many_equals_lexsort_merge(cap, m):
+    """Random multi-batch histories with ties, from an all-padding first
+    batch until a batch fills the store exactly: after every merge the
+    whole store is bitwise the NumPy lexsort merge."""
+    rng = np.random.default_rng(cap * 100 + m)
+    state = timestore.make_state(cap, {"v": jnp.float32, "c": jnp.int32})
+    n = 0
+    while int(state["count"]) < cap:
+        keys, ts, values = _random_batch(rng, m, n)
+        want = _lexsort_merge(state, keys, ts, values)
+        state = timestore.insert_many(
+            state, jnp.asarray(keys), jnp.asarray(ts),
+            {c: jnp.asarray(v) for c, v in values.items()}, n)
+        _assert_store_bitwise(state, want)
+        left = cap - int(state["count"])
+        n = left if left <= m else int(rng.integers(0, m + 1))
+
+
+@pytest.mark.parametrize("m", [1, 16])
+def test_insert_many_stacked_equals_per_shard(m):
+    """The vmapped merge over 4 stacked shards, each with its own history
+    and batch, is ``insert_many`` run on each shard."""
+    rng = np.random.default_rng(m)
+    cap, shards, blocks = 50, [], []
+    for s in range(4):
+        st = timestore.make_state(cap, {"v": jnp.float32, "c": jnp.int32})
+        for _ in range(3):
+            n = int(rng.integers(0, m + 1))
+            k, t, v = _random_batch(rng, m, n)
+            st = timestore.insert_many(st, k, t, v, n)
+        n = int(rng.integers(0, m + 1)) if s else 0     # shard 0: padding
+        shards.append(st)
+        blocks.append(_random_batch(rng, m, n) + (n,))
+    stack = lambda *xs: np.stack([np.asarray(x) for x in xs])
+    got = timestore.insert_many_stacked(
+        jax.tree_util.tree_map(stack, *shards),
+        stack(*[b[0] for b in blocks]), stack(*[b[1] for b in blocks]),
+        {c: stack(*[b[2][c] for b in blocks]) for c in ("v", "c")},
+        stack(*[b[3] for b in blocks]))
+    for s, (st, (k, t, v, n)) in enumerate(zip(shards, blocks)):
+        want = timestore.insert_many(st, k, t, v, n)
+        for a, b in zip(jax.tree_util.tree_leaves(want),
+                        jax.tree_util.tree_leaves(got)):
+            np.testing.assert_array_equal(
+                np.asarray(a).view(np.int32), np.asarray(b)[s].view(np.int32))
 
 
 def test_put_many_overflow_and_empty():

@@ -180,17 +180,37 @@ def _sorted_shapes(hlo_text: str):
             for line in hlo_text.splitlines() if " sort(" in line]
 
 
-@pytest.mark.parametrize("n_shards", [1, 4])
-def test_store_merge_sorts_only_the_batch(topo, n_shards):
-    """The ingest merge (``insert_many``, and its stacked form over one
-    shard per chip) at the smoke's store size sorts only the incoming
-    rows: a sort over the 2**20-row store takes minutes to compile for a
-    TPU, once per follower device."""
+def _index_moves(hlo_text: str):
+    """(line, shapes) of every ``gather`` and ``scatter`` in compiled HLO
+    text: the shapes a gather's indices reach (its result) and a
+    scatter's indices and updates."""
+    import re
+
+    ins = re.compile(r"\s+(?:ROOT )?%(\S+) = (.+?[\]})]) ([a-z][\w-]*)\(")
+    types = {m.group(1): m.group(2) for m in map(ins.match,
+                                                 hlo_text.splitlines()) if m}
+    moves = []
+    for line in hlo_text.splitlines():
+        m = ins.match(line)
+        if m and m.group(3) == "gather":
+            moves.append((line, [m.group(2)]))
+        elif m and m.group(3) == "scatter":
+            args = re.findall(r"%(\S+?)[,)]", line.split(" scatter(")[1])
+            moves.append((line, [types.get(a, "") for a in args[1:3]]))
+    return moves
+
+
+@pytest.fixture(scope="module", params=[1, 4])
+def store_merge(request, topo):
+    """(store rows per shard, batch pad, compiled HLO text) of the ingest
+    merge (``insert_many``, and its stacked form over one shard per chip)
+    at the smoke's store size."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.storage import timestore
 
+    n_shards = request.param
     rows, m = SMOKE_CAPACITY // n_shards, 512
     if n_shards == 1:
         sh, lead, fn = (SingleDeviceSharding(topo.devices[0]), (),
@@ -210,11 +230,35 @@ def test_store_merge_sorts_only_the_batch(topo, n_shards):
         state, batch, batch,
         {c: _sds(lead + (m,), jnp.float32, sh) for c in cols},
         _sds(lead, jnp.int32, sh)).compile()
-    sorted_shapes = _sorted_shapes(compiled.as_text())
+    return rows, m, compiled.as_text()
+
+
+def test_store_merge_sorts_only_the_batch(store_merge):
+    """The ingest merge sorts only the incoming rows: a sort over the
+    2**20-row store takes minutes to compile for a TPU, once per
+    follower device."""
+    _, m, hlo = store_merge
+    sorted_shapes = _sorted_shapes(hlo)
     assert sorted_shapes, "the batch is no longer sorted"
     for shapes in sorted_shapes:
         for shape in shapes:
             assert int(shape.split(",")[-1]) == m, sorted_shapes
+
+
+def test_store_merge_streams_the_store(store_merge):
+    """The ingest merge moves the store by static shifts: no gather or
+    scatter by a store-length index array, and no loop at all (its
+    binary searches are unrolled).  A search of every slot and a gather
+    per column took ~2 s per 1,024-row apply at 2**23 rows on a v5e."""
+    import re
+
+    rows, _, hlo = store_merge
+    moves = _index_moves(hlo)
+    assert moves, "the parser finds no gather or scatter"
+    store = re.compile(rf"[\[,]{rows}[\],]")
+    assert not [line for line, shapes in moves
+                if any(store.search(s) for s in shapes)]
+    assert " while(" not in hlo
 
 
 def test_sharded_online_step_compiles_for_four_chips(topo, smoke_engine):
