@@ -11,6 +11,12 @@ harness finds the rest by name:
   the configuration names them;
 * ``bench/metrics/<metric>.py``: the reader of each per-layer metric.
 
+A metric named ``<base>.<qualifier>`` that has no reading or reader of
+its own is ``<base>``, reported under its own name: so cells that need
+their own bound, or whose per-layer metrics move that metric, split a
+metric by data alone (``p99_ms.sharded`` is the serve mode's
+``p99_ms``; ``batch_fill.sharded`` reads as ``batch_fill``).
+
 Standard output ends with one JSON line; standard error ends with the
 numbers that decided ``correct``, each beside its limit.
 """
@@ -48,6 +54,16 @@ def load_module(path: Path, name: Optional[str] = None) -> ModuleType:
     return mod
 
 
+def unqualified(name: str, has) -> str:
+    """``name`` where ``has(name)``, else the longest base of it
+    (``a.b.c``, ``a.b``, ``a``) for which ``has`` holds; ``name`` where
+    none does."""
+    base = name
+    while not has(base) and "." in base:
+        base = base.rsplit(".", 1)[0]
+    return base if has(base) else name
+
+
 class Registry:
     """What ``BENCHMARK.json`` and the files under ``bench/`` name."""
 
@@ -79,6 +95,11 @@ class Registry:
     def module(self, kind: str, name: str) -> ModuleType:
         return load_module(self.bench / kind / f"{name}.py")
 
+    def reader(self, metric: str) -> ModuleType:
+        """The reader of per-layer metric ``metric``."""
+        return self.module("metrics", unqualified(
+            metric, lambda n: (self.bench / "metrics" / f"{n}.py").is_file()))
+
     def metrics_for(self, cell: str, kind: str) -> List[dict]:
         """The ``end_to_end`` or ``per_layer`` metrics ``cell`` reports."""
         return [m for m in self.spec[kind]
@@ -104,6 +125,10 @@ class Context:
                  devices: list):
         self.reg, self.cell = reg, cell
         self.cfg = reg.config(cell["config"])
+        if self.cfg.get("shards", cell["chips"]) != cell["chips"]:
+            raise BenchError(f"configuration {cell['config']!r} states "
+                             f"{self.cfg['shards']} shards, one per chip, "
+                             f"and the cell has {cell['chips']} chips")
         self.mix = reg.traffic(cell["traffic"])
         self.data = reg.module("data", self.cfg["data"])
         self.reference = reg.module("references", self.cfg["reference"])
@@ -181,8 +206,10 @@ class Context:
             self._trace_dir.cleanup()
 
     def memory_peak_bytes(self) -> int:
+        """The peak of the fullest chip; prints every chip's."""
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                  for d in self.devices]
+        print(f"memory_peak_bytes per device: {peaks}", flush=True)
         return int(max(peaks))
 
 
@@ -238,7 +265,7 @@ def run_cell(ctx: Context) -> dict:
         view = LayerView(red, out.layer, peaks)
         metrics = {}
         for m in ctx.reg.metrics_for(name, "per_layer"):
-            v = ctx.reg.module("metrics", m["name"]).read(view)
+            v = ctx.reg.reader(m["name"]).read(view)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         result_device_extra = {"busy_s": red.busy_s(),
@@ -248,9 +275,10 @@ def run_cell(ctx: Context) -> dict:
     else:
         metrics = {}
         for m in ctx.reg.metrics_for(name, "end_to_end"):
-            if m["name"] not in out.metrics:
+            key = unqualified(m["name"], out.metrics.__contains__)
+            if key not in out.metrics:
                 raise BenchError(f"the run measured no {m['name']}")
-            metrics[m["name"]] = {"value": out.metrics[m["name"]],
+            metrics[m["name"]] = {"value": out.metrics[key],
                                   "unit": m["unit"]}
         result_device_extra = {}
     correct = all(v <= lim for v, lim in out.checks.values())

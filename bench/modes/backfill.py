@@ -34,7 +34,7 @@ def run(ctx) -> Outcome:
     print(f"compile cache: {system.enable_compile_cache()}", flush=True)
     tables = system.make_tables(data.SCHEMA, data.DICTS, hist)
     t_data = time.perf_counter()
-    engine = system.build_engine(ctx.cfg["sql"], tables, cfg)
+    engine = system.build_engine(ctx.cfg["sql"], tables, cfg, ctx.devices)
     # warm: one whole call compiles every program the calls run
     engine.offline(system.shift_tables(tables, order_col, 0))
     t_warm = time.perf_counter()

@@ -22,7 +22,7 @@ from typing import Dict
 import numpy as np
 
 from bench import compare
-from bench.harness import Outcome
+from bench.harness import BenchError, Outcome
 
 
 def arrivals(rate: float, n: int, seed: int) -> np.ndarray:
@@ -98,7 +98,9 @@ def run(ctx) -> Outcome:
                if ing else [])
     n_req = int(math.ceil(rate * seconds * 1.25)) + 2 * batch
     req = data.requests(cfg, seed, n_req, ing_rows)
-    warm = data.requests(cfg, seed, 2 * batch, ing_rows, stream=3)
+    # a sharded engine's warm-up wants a full batch's worth on one shard
+    warm = data.requests(cfg, seed, 2 * batch * cfg.get("shards", 1),
+                         ing_rows, stream=3)
     due_off = arrivals(rate, n_req, seed)
     rows = data.client_rows(req)
     warm_rows = data.client_rows(warm)
@@ -111,22 +113,26 @@ def run(ctx) -> Outcome:
     print(f"compile cache: {system.enable_compile_cache()}", flush=True)
     tables = system.make_tables(data.SCHEMA, data.DICTS, hist)
     t_data = time.perf_counter()
-    engine = system.build_engine(ctx.cfg["sql"], tables, cfg)
+    engine = system.build_engine(ctx.cfg["sql"], tables, cfg, ctx.devices)
     for name, t in tables.items():
         engine.bulk_load(name, t)
     loop = system.build_loop(engine, mix)
+    placed = system.store_devices(engine)
+    print(f"store arrays on {len(placed)} devices: "
+          f"{sorted(str(d) for d in placed)}", flush=True)
+    if "shards" in cfg and placed != set(ctx.devices):
+        raise BenchError(f"the store of {cfg['shards']} shards lies on "
+                         f"{len(placed)} devices, not one per chip")
     t_load = time.perf_counter()
 
     # ---- warm every shape the window uses ------------------------------
     classes = system.serving_pad_classes(engine, batch)
     print(f"request pad classes to warm (static retrace bound): {classes}",
           flush=True)
-    w = 0
-    for k in classes:
-        for _j in range(k):
-            loop.submit(warm_rows[w % len(warm_rows)],
-                        now=time.perf_counter())
-            w += 1
+    for idx in system.warm_batches(engine, warm[ref_mod.KEY_COLUMN],
+                                   classes):
+        for j in idx:
+            loop.submit(warm_rows[j], now=time.perf_counter())
         loop.flush(now=time.perf_counter())
     if writer is not None:
         # applies until every table of the rotation has had one: the
@@ -292,14 +298,22 @@ def run(ctx) -> Outcome:
     wait_ms = (flush_at[:n_due] - due[:n_due]) * 1e3
     layer = {"queue_wait_ms": np.where(np.isnan(wait_ms), ceiling_ms,
                                        wait_ms),
+             "latency_ms": lat_ms,
              "loop_stats": dict(loop.stats), "batch_size": batch,
-             "control": control, "p99_halves": halves}
+             "control": control, "p99_halves": halves,
+             "devices": len(ctx.devices)}
     if ctx.trace:
         in_flush = np.asarray([i for _, _, idx in flushes for i in idx],
                               np.int64)
         qf = {c: v[in_flush] for c, v in req.items()}
         layer["flush_bytes"] = float(ref_mod.request_bytes(
             reference.window_rows(qf, acked_at[in_flush])).sum())
+        if "shards" in cfg:
+            # per flush, the requests routed to each shard
+            owner = system.key_owners(engine, req[ref_mod.KEY_COLUMN])
+            layer["shard_counts"] = np.stack(
+                [np.bincount(owner[idx], minlength=cfg["shards"])
+                 for _, _, idx in flushes])
     metrics = {"p99_ms": p99, "setup_s": setup_s}
     if writer is not None:
         metrics["ingest_rows_per_s"] = applied_in / (t_applied - t_win)

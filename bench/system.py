@@ -8,7 +8,7 @@ deployed ``FeatureEngine`` and its ``ServeLoop``.  Everything else in
 
 from __future__ import annotations
 
-from typing import Dict, Mapping, Sequence
+from typing import Dict, List, Mapping, Sequence
 
 import numpy as np
 
@@ -20,7 +20,7 @@ from repro.serve.loop import AdmissionError, ServeLoop
 
 __all__ = ["AdmissionError", "ServeLoop", "build_engine", "build_loop",
            "enable_compile_cache", "make_tables", "serving_pad_classes",
-           "shift_tables"]
+           "key_owners", "shift_tables", "store_devices", "warm_batches"]
 
 
 def make_tables(schema: Mapping[str, Sequence], dicts: Mapping[str, Sequence],
@@ -53,8 +53,26 @@ def shift_tables(tables: Mapping[str, Table], order_col: str, by: int
         for n, t in tables.items()}
 
 
-def build_engine(sql: str, tables: Mapping[str, Table], cfg) -> FeatureEngine:
-    return FeatureEngine(sql, dict(tables), capacity=cfg["capacity"])
+def build_engine(sql: str, tables: Mapping[str, Table], cfg,
+                 devices: Sequence = ()) -> FeatureEngine:
+    """The deployed engine.  A configuration with ``shards`` partitions
+    its keys over that many tablets, one per device of the cell, on a
+    1-D mesh of exactly those devices (``capacity`` is per shard);
+    without it the store is one unsharded table per chip."""
+    if "shards" not in cfg:
+        return FeatureEngine(sql, dict(tables), capacity=cfg["capacity"])
+    from jax.sharding import Mesh
+
+    return FeatureEngine(sql, dict(tables), capacity=cfg["capacity"],
+                         mesh=Mesh(np.asarray(devices), ("shard",)))
+
+
+def store_devices(engine: FeatureEngine) -> set:
+    """The devices that hold the store's arrays."""
+    import jax
+
+    return {d for x in jax.tree_util.tree_leaves(engine.store.tables)
+            for d in x.sharding.device_set}
 
 
 def build_loop(engine: FeatureEngine, mix) -> ServeLoop:
@@ -67,8 +85,33 @@ def build_loop(engine: FeatureEngine, mix) -> ServeLoop:
 
 def serving_pad_classes(engine: FeatureEngine, max_batch: int):
     """The request pad classes a batch of up to ``max_batch`` can reach,
-    by the program's static retrace bound: the shapes to warm."""
+    by the program's static retrace bound: the shapes to warm.  A
+    sharded engine pads each shard's sub-batch to its busiest shard's
+    count (``online_sharded_batch``)."""
     from repro.core.analysis.retrace import retrace_bound
 
+    path = "online_sharded_batch" if engine.sharded else "online_batch"
     return retrace_bound(engine.cs, max_batch=max_batch)[
-        "drivers"]["online_batch"]["pad_classes"]
+        "drivers"][path]["pad_classes"]
+
+
+def key_owners(engine: FeatureEngine, keys) -> np.ndarray:
+    """The shard that owns each key, by the store's routing."""
+    return engine.store.owner_of_keys(np.asarray(keys))
+
+
+def warm_batches(engine: FeatureEngine, keys: np.ndarray, classes
+                 ) -> List[List[int]]:
+    """Per pad class ``c``, the indices (into ``keys``, the warm-up
+    requests' keys) of one batch that runs the executable of class
+    ``c``: any ``c`` requests for an unsharded engine; for a sharded
+    one, ``c`` requests of keys that one shard owns, so that its
+    busiest shard gets exactly ``c``."""
+    if not engine.sharded:
+        return [list(range(c)) for c in classes]
+    owner = key_owners(engine, keys)
+    mine = np.flatnonzero(owner == np.bincount(owner).argmax())
+    if len(mine) < max(classes):
+        raise ValueError(f"{len(mine)} warm-up requests on the busiest "
+                         f"shard, the largest class is {max(classes)}")
+    return [mine[:c].tolist() for c in classes]

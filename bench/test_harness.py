@@ -72,3 +72,34 @@ def test_added_files_found_by_name(toy_root, cpu_harness, capsys):
     assert line["metrics"]["served.count"]["value"] >= line["attempted"]
     assert 100 <= line["attempted"] <= 200        # 150 requests/s, 1 s
     assert "p99_ms" not in line["metrics"]         # traced: per-layer only
+
+
+def test_every_per_layer_metric_has_a_reader():
+    """Each per-layer metric of each cell finds its reader by name, and
+    moves an end-to-end metric that its cells report."""
+    from bench.harness import Registry
+
+    reg = Registry()
+    for cell in reg.spec["workloads"]:
+        e2e = {m["name"] for m in reg.metrics_for(cell["name"], "end_to_end")}
+        for m in reg.metrics_for(cell["name"], "per_layer"):
+            assert Path(reg.reader(m["name"]).__file__).is_file()
+            assert m["moves"] in e2e, (cell["name"], m["name"])
+
+
+def test_serve_ingest_tail_reader():
+    """``p99_ms.ingest`` is the nearest-rank 99th percentile of every
+    request's latency, and reads nothing where there are none."""
+    from types import SimpleNamespace
+
+    import numpy as np
+
+    from bench.harness import load_module
+
+    read = load_module(ROOT / "bench" / "metrics" / "p99_ms.ingest.py").read
+    lat = np.random.default_rng(5).permutation(np.arange(1.0, 201.0))
+    assert read(SimpleNamespace(layer={"latency_ms": lat})) == 198.0
+    assert read(SimpleNamespace(layer={"latency_ms": np.array([7.5])})) \
+        == 7.5
+    assert read(SimpleNamespace(layer={"latency_ms": np.array([])})) is None
+    assert read(SimpleNamespace(layer={})) is None
